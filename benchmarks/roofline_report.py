@@ -181,24 +181,6 @@ def run(quick: bool = False, mesh: str = "pod"):
     return rows
 
 
-def run_isolated(quick: bool = False, mesh: str = "pod"):
-    """Entry point for the ``benchmarks.run`` harness: fresh interpreter —
-    the vr-traffic cross-check initializes jax, and the harness process
-    must keep its device view untouched for the other suites (same rule
-    as ``train_throughput.run_isolated``)."""
-    import subprocess
-
-    cmd = [sys.executable, "-m", "benchmarks.roofline_report"]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
-                          timeout=1800)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"roofline_report failed:\n{proc.stderr[-3000:]}")
-
-
 def markdown_table(mesh: str = "pod") -> str:
     recs = load(mesh)
     lines = [

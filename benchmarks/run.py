@@ -8,16 +8,28 @@ from __future__ import annotations
 
 import argparse
 import os
+import subprocess
 import sys
-import traceback
 
-# script form: `python benchmarks/run.py` puts benchmarks/ (not the repo
-# root) on sys.path, so the `from benchmarks import ...` below needs the
-# root added — the CI benchmark-smoke job invokes this spelling — and
-# repro_bootstrap adds src/ when repro isn't pip-installed
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
-import repro_bootstrap  # noqa: F401,E402
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# suite name -> module under benchmarks/ exposing run(quick=...); the
+# children start from the repo root, where repro_bootstrap adds src/ when
+# repro isn't pip-installed
+SUITES = {
+    "fig1": "fig1_single_worker",
+    "fig2": "fig2_distributed",
+    "fig3": "fig3_large",
+    "table1": "table1_accounting",
+    "theory": "theory_rates",
+    "tau": "tau_sweep",
+    "variance": "variance",
+    "drivers": "driver_throughput",
+    "spmd": "spmd_scaling",
+    "train": "train_throughput",
+    "serve": "serve_throughput",
+    "roofline": "roofline_report",
+}
 
 
 def main(argv=None) -> None:
@@ -25,45 +37,26 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="reduced sizes (CI-speed)")
     ap.add_argument("--only", default="",
-                    help="comma list: fig1,fig2,fig3,table1,theory,tau,"
-                         "variance,drivers,spmd,train,serve,roofline")
+                    help="comma list: " + ",".join(SUITES))
     args = ap.parse_args(argv)
 
-    from benchmarks import (driver_throughput, fig1_single_worker,
-                            fig2_distributed, fig3_large, roofline_report,
-                            serve_throughput, spmd_scaling,
-                            table1_accounting, tau_sweep, theory_rates,
-                            train_throughput, variance)
-
-    suites = {
-        "fig1": fig1_single_worker.run,
-        "fig2": fig2_distributed.run,
-        "fig3": fig3_large.run,
-        "table1": table1_accounting.run,
-        "theory": theory_rates.run,
-        "tau": tau_sweep.run,
-        "variance": variance.run,
-        "drivers": driver_throughput.run,
-        # subprocess suites: own interpreter (forced multi-device host
-        # platform, or — roofline — a fresh jax for the vr-traffic check)
-        "spmd": spmd_scaling.run_isolated,
-        "train": train_throughput.run_isolated,
-        "serve": serve_throughput.run_isolated,
-        "roofline": roofline_report.run_isolated,
-    }
     only = [s for s in args.only.split(",") if s]
     failures = []
-    print("name,us_per_call,derived")
-    for name, fn in suites.items():
+    print("name,us_per_call,derived", flush=True)
+    for name, module in SUITES.items():
         if only and name not in only:
             continue
-        try:
-            fn(quick=args.quick)
-        except Exception as e:  # noqa: BLE001
-            failures.append((name, repr(e)))
-            traceback.print_exc()
+        # every suite runs in its own interpreter, one after another, and
+        # this process never touches jax: a chip belongs to one process at
+        # a time, and the spmd/train/serve suites force a host-device
+        # count that must precede backend start-up
+        code = (f"import repro_bootstrap; from benchmarks import {module}; "
+                f"{module}.run(quick={args.quick})")
+        rc = subprocess.run([sys.executable, "-c", code], cwd=ROOT).returncode
+        if rc:
+            failures.append((name, rc))
     if failures:
-        print(f"FAILED suites: {failures}", file=sys.stderr)
+        print(f"FAILED suites (name, exit code): {failures}", file=sys.stderr)
         raise SystemExit(1)
 
 

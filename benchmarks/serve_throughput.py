@@ -27,8 +27,8 @@ rather than measured-vs-remembered):
     interpret-mode fused rows).
 
 Every row records ``cold_s`` (warmup compile) vs ``warm_s`` (steady run
-wall) — with ``--compile-cache`` / ``REPRO_COMPILE_CACHE`` set, cold_s
-shrinks to deserialization time on the second process launch.
+wall) — the persistent cache (``launch/compile_cache.py``) is on, so
+cold_s shrinks to deserialization time on the second process launch.
 
 Writes ``BENCH_serve.json`` at the repo root plus the standard results
 CSV.  Must start in a fresh process: it forces 2 simulated host devices
@@ -97,7 +97,7 @@ def run(quick: bool = False):
     from repro.models import model
     from repro.serve import ServeEngine, run_host_loop, synthetic_trace
 
-    enable_compile_cache()                 # honors REPRO_COMPILE_CACHE
+    cache_dir = enable_compile_cache()
     cfg = get_arch("qwen2-7b").reduced()
     params = model.init_params(cfg, jax.random.PRNGKey(0))
     repeat = 2
@@ -169,8 +169,7 @@ def run(quick: bool = False):
         "config": {"arch": cfg.name, "quick": quick,
                    "device_count": jax.device_count(),
                    "backend_platform": jax.default_backend(),
-                   "compile_cache": os.environ.get("REPRO_COMPILE_CACHE",
-                                                   "")},
+                   "compile_cache": cache_dir},
         "rows": rows,
     }
     with open(os.path.join(ROOT, "BENCH_serve.json"), "w") as f:
@@ -181,22 +180,6 @@ def run(quick: bool = False):
     print(f"decode_speedup_vs_host={gate['decode_speedup_vs_host']:.2f}x "
           f"prefill_speedup_vs_host={pf['prefill_speedup_vs_host']:.2f}x")
     return payload
-
-
-def run_isolated(quick: bool = False):
-    """Entry point for the ``benchmarks.run`` harness: fresh interpreter,
-    because the forced host-device count must precede jax init."""
-    import subprocess
-    import sys
-
-    cmd = [sys.executable, "-m", "benchmarks.serve_throughput"]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
-                          timeout=1800)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        raise RuntimeError(f"serve_throughput failed:\n{proc.stderr[-3000:]}")
 
 
 if __name__ == "__main__":

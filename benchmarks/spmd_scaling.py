@@ -96,24 +96,6 @@ def run(quick: bool = False):
     return payload
 
 
-def run_isolated(quick: bool = False):
-    """Entry point for the ``benchmarks.run`` harness: launch a fresh
-    interpreter, because the forced host-device count must be set before
-    jax initializes and every other suite must keep the real single-device
-    view (see tests/conftest.py)."""
-    import subprocess
-    import sys
-
-    cmd = [sys.executable, "-m", "benchmarks.spmd_scaling"]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
-                          timeout=1800)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        raise RuntimeError(f"spmd_scaling failed:\n{proc.stderr[-3000:]}")
-
-
 if __name__ == "__main__":
     import sys
     run(quick="--quick" in sys.argv)

@@ -18,7 +18,7 @@ trajectory pins bit-exact in f64 against ``run_async`` /
 Why a KV-store data plane instead of cross-process ``shard_map``: XLA
 cannot compile multi-process computations on the CPU backend (it raises
 ``Multiprocess computations aren't implemented on the CPU backend``), so
-on this container each process runs its owned workers' epochs as LOCAL
+on the CPU each process runs its owned workers' epochs as LOCAL
 jitted programs and the paper's central server lives in the wave-boundary
 delta exchange.  On a real accelerator backend the same worker partition
 maps onto a global 1-D device mesh (``spmd.process_worker_mesh``) and the

@@ -551,11 +551,12 @@ def solve(spec: RunSpec, problem, *, key=None, mesh=None,
 
     Uniform handling across every registry algorithm:
 
-      * ``backend="spmd"``: simulated host devices are forced *before*
-        the first jax operation (``spmd.force_host_devices``; a fresh
-        process acquires them, an already-initialized one validates the
-        count) and the driver gets one worker per device of ``mesh``
-        (default: the first p devices);
+      * ``backend="spmd"``: the driver gets one worker per device of
+        ``mesh`` (default: the first p devices).  On a chip those are p
+        chips, and fewer fail naming the count found; on the CPU, p
+        simulated host devices are forced *before* the first jax
+        operation (``spmd.force_host_devices``; a fresh process acquires
+        them, an already-initialized one validates the count);
       * data sharding/merging per the algorithm's topology
         (:func:`_coerce_problem`);
       * ``eta=None`` resolves to ``convex.auto_eta`` on the merged

@@ -9,26 +9,23 @@ the same local-epoch primitives run under ``jax.shard_map`` over a real
 own device and the paper's central server becomes collective communication
 (``jax.lax.pmean`` over the worker axis) instead of a ``mean(axis=0)``.
 
-On this container the mesh is CPU-simulated: ``force_host_devices(n)``
-(shared by ``launch/mesh.py`` and the tests) forces the host platform to
-present n devices via XLA_FLAGS — it must run before the jax backend
-initializes, but after ``import jax`` is fine (device state is lazy).
+On the chip, the mesh is the chip's devices: ``worker_mesh(p)`` takes
+the first p and fails, naming the count, when there are fewer.  On the
+CPU (tests), ``force_host_devices(n)`` (shared by ``launch/mesh.py`` and
+the tests) makes the host platform present n devices via XLA_FLAGS — it
+must run before the jax backend initializes, but after ``import jax`` is
+fine (device state is lazy).
 
 Sampling is data, not code (the async event schedule's rule, DESIGN.md §3,
 extended to RNG): every permutation/index draw is precomputed on the host
 with EXACTLY the key splits the vmap drivers perform, then shipped to the
-mesh sharded along the worker axis.  This is deliberate — on this jax
-version, XLA's multi-device CPU partitioner miscompiles in-shard
-``jax.random.permutation``/``randint`` in larger programs (every device
-silently receives device 0's draw; the spmd/vmap disagreement that exposed
-it is pinned by ``tests/test_spmd_backend.py``), and shipping the draws
-also guarantees both backends consume identical randomness by
-construction, so the only numerical divergence left is collective
-reduction order.  (``check_rep=False`` on every runner for a related
-reason: this jax version's replication checker rejects scan carries that
-enter unreplicated and leave pmean-replicated, which is the shape of
-every round loop here; correctness is pinned by the vmap-agreement tests
-instead.)
+mesh sharded along the worker axis.  Shipping the draws guarantees both
+backends consume identical randomness by construction, so the only
+numerical divergence left is collective reduction order (the spmd/vmap
+agreement is pinned by ``tests/test_spmd_backend.py``).  Every runner
+passes ``check_vma=False``: the round loops carry state that enters
+unreplicated and leaves pmean-replicated, and correctness is pinned by
+the vmap-agreement tests instead.
 
 The asynchronous drivers (CentralVR-Async, D-SAGA) run their deterministic
 event schedule as ROUNDS OF CONCURRENT EVENTS: ``runtime.wave_partition``
@@ -65,7 +62,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import convex, runtime
@@ -87,14 +83,15 @@ def force_host_devices(n: int) -> None:
 
     Safe to call after ``import jax`` but only before the backend
     initializes (first ``jax.devices()`` / first op); afterwards it is a
-    no-op if enough devices already exist and an error otherwise.  Both
-    ``launch/mesh.py`` and the spmd tests go through here so the flag is
-    spelled in exactly one place.
+    no-op if enough devices already exist or the devices are an
+    accelerator's (``worker_mesh`` then names the count it found), and an
+    error otherwise.  Both ``launch/mesh.py`` and the spmd tests go
+    through here so the flag is spelled in exactly one place.
     """
     from jax._src import xla_bridge
 
     if xla_bridge.backends_are_initialized():
-        if jax.device_count() >= n:
+        if jax.device_count() >= n or jax.default_backend() != "cpu":
             return
         raise RuntimeError(
             f"jax already initialized with {jax.device_count()} device(s); "
@@ -117,11 +114,15 @@ def worker_mesh(p: int) -> Mesh:
     """A 1-D mesh of p devices, one CentralVR worker per device."""
     devs = jax.devices()
     if len(devs) < p:
+        platform = devs[0].platform
+        hint = (f"call repro.core.spmd.force_host_devices({p}) before the "
+                f'first jax operation (or set XLA_FLAGS="{_COUNT_FLAG}={p}")'
+                if platform == "cpu" else
+                "each worker takes one chip, so use at most that many "
+                "workers or run on a host with more chips")
         raise RuntimeError(
-            f"spmd backend needs {p} devices, found {len(devs)}; on CPU "
-            f"call repro.core.spmd.force_host_devices({p}) before the "
-            f"first jax operation (or set "
-            f'XLA_FLAGS="{_COUNT_FLAG}={p}")')
+            f"spmd backend needs {p} devices, found {len(devs)} "
+            f"{platform} device(s); {hint}")
     return Mesh(np.asarray(devs[:p]), (WORKER_AXIS,))
 
 
@@ -267,11 +268,11 @@ def _sync_runner(mesh: Mesh, kind: str, fused=None, prox=None):
                                               perms)
         return x, table[None], gbar, rels
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(WORKER_AXIS), P(None, WORKER_AXIS)),
-        out_specs=(P(), P(WORKER_AXIS), P(), P()), check_rep=False))
+        out_specs=(P(), P(WORKER_AXIS), P(), P()), check_vma=False))
 
 
 def run_sync(sp, *, eta: float, rounds: int, key: jax.Array,
@@ -347,11 +348,11 @@ def _dsvrg_runner(mesh: Mesh, kind: str, fused=None, prox=None,
 
         return jax.lax.scan(round_, x0, (idx, snap))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(None, WORKER_AXIS), P()),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def run_dsvrg(sp, *, eta: float, rounds: int, key: jax.Array, tau: int = 0,
@@ -403,11 +404,11 @@ def _dist_sgd_runner(mesh: Mesh, kind: str):
 
         return jax.lax.scan(round_, x0, (idx, etas))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(),
                   P(None, WORKER_AXIS), P()),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def run_dist_sgd(sp, *, eta: float, rounds: int, key: jax.Array,
@@ -460,11 +461,11 @@ def _easgd_runner(mesh: Mesh, kind: str):
         (xc, xl), rels = jax.lax.scan(round_, (xc0, xl0), (idx, etas))
         return xc, xl[None], rels
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(None, WORKER_AXIS), P()),
-        out_specs=(P(), P(WORKER_AXIS), P()), check_rep=False))
+        out_specs=(P(), P(WORKER_AXIS), P()), check_vma=False))
 
 
 def run_easgd(sp, *, eta: float, rounds: int, key: jax.Array, tau: int = 16,
@@ -512,11 +513,11 @@ def _ps_svrg_runner(mesh: Mesh, kind: str):
 
         return jax.lax.scan(round_, x0, idx)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(None, None, WORKER_AXIS)),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
 
 def run_ps_svrg(sp, *, eta: float, rounds: int, key: jax.Array,
@@ -640,12 +641,12 @@ def _async_runner(mesh: Mesh, kind: str, fused=None, prox=None):
         return (x_c, gbar_c, table[None], x_old[None], gbar_old[None],
                 x_fetch[None], gbar_fetch[None], rels)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(WORKER_AXIS), P(), P(), P(None, None, WORKER_AXIS)),
         out_specs=(P(), P(), P(WORKER_AXIS), P(WORKER_AXIS), P(WORKER_AXIS),
-                   P(WORKER_AXIS), P(WORKER_AXIS), P()), check_rep=False))
+                   P(WORKER_AXIS), P(WORKER_AXIS), P()), check_vma=False))
 
 
 def _wave_inputs(mesh, sp, schedule, draws):
@@ -761,12 +762,12 @@ def _dsaga_runner(mesh: Mesh, kind: str, literal_scaling: bool, fused=None,
         return (x_c, gbar_c, table[None], x_old[None], gbar_old[None],
                 x_fetch[None], gbar_fetch[None], rels)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(), P(), P(),
                   P(), P(), P(None, None, WORKER_AXIS)),
         out_specs=(P(), P(), P(WORKER_AXIS), P(WORKER_AXIS), P(WORKER_AXIS),
-                   P(WORKER_AXIS), P(WORKER_AXIS), P()), check_rep=False))
+                   P(WORKER_AXIS), P(WORKER_AXIS), P()), check_vma=False))
 
 
 def run_dsaga(sp, *, eta: float, rounds: int, key: jax.Array, tau: int = 100,

@@ -1,38 +1,37 @@
-"""Persistent XLA compilation cache shared by every launcher.
+"""Persistent XLA compilation cache shared by every launcher and
+``chip_smoke.py``.
 
-First thin slice of the ROADMAP cold-start item: ``--compile-cache DIR``
-(or ``REPRO_COMPILE_CACHE=DIR``) points JAX's persistent compilation
-cache at a directory, so the second process-launch of the same program
-deserializes executables instead of recompiling — the serve bench
-records the cold-vs-warm delta per row.  Thresholds are zeroed so even
-sub-second CPU test programs are cached (the default 1s floor would skip
-everything the reduced configs compile).
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, the cache lives there and no
+other directory is set. Otherwise it lives at one fixed path inside the
+checkout, ``<repo>/.jax_cache``: a directory that moved between runs
+would never hit. Thresholds are zeroed so even sub-second programs are
+cached (the default 1s floor would skip everything the reduced configs
+compile), so a second process launch of the same program deserializes
+executables instead of recompiling.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-ENV_VAR = "REPRO_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Enable the persistent cache at ``path`` (or $REPRO_COMPILE_CACHE).
-    Returns the absolute cache dir, or None if neither is set.  Must run
-    before the first compilation; safe to call more than once."""
-    path = path or os.environ.get(ENV_VAR) or None
-    if not path:
-        return None
-    path = os.path.abspath(path)
+def cache_dir() -> str:
+    """Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` if set, else
+    the fixed checkout path."""
+    return os.path.abspath(os.environ.get(ENV_VAR) or DEFAULT_DIR)
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent cache at :func:`cache_dir` and return it.
+    Must run before the first compilation; safe to call more than once."""
+    path = cache_dir()
     os.makedirs(path, exist_ok=True)
     import jax
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
-
-
-def add_compile_cache_arg(parser) -> None:
-    parser.add_argument("--compile-cache", default=None, metavar="DIR",
-                        help="persistent XLA compilation cache dir "
-                             f"(default: ${ENV_VAR} if set)")

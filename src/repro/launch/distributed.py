@@ -361,10 +361,34 @@ def _verify(args, results: dict) -> int:
     return 0 if ok else 1
 
 
+def _accelerator_platform() -> Optional[str]:
+    """The accelerator JAX would take on this host, or None. Probed in a
+    child, so the launcher itself never holds a device; skipped when the
+    environment pins JAX to the CPU."""
+    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
+        return None
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=300)
+    lines = probe.stdout.split()
+    platform = lines[-1] if probe.returncode == 0 and lines else "cpu"
+    return None if platform == "cpu" else platform
+
+
 def run_launcher(args) -> int:
     if args.elastic and args.drop_process == 0:
         print("--drop-process 0 is invalid: process 0 co-hosts the "
               "coordination service", file=sys.stderr)
+        return 2
+    platform = _accelerator_platform()
+    if platform is not None:
+        # the one-host process tier is a CPU rehearsal of multi-host
+        # (core/procmesh.py): its processes would contend for one chip
+        print(f"refusing to start {args.nprocs} processes on a host with a "
+              f"{platform} accelerator: they would contend for it, and "
+              "--verify would hold it in this process.  The one-host "
+              "process tier is a CPU rehearsal of multi-host; run it with "
+              "JAX_PLATFORMS=cpu", file=sys.stderr)
         return 2
     logdir = args.logdir or tempfile.mkdtemp(prefix="repro-multihost-")
     os.makedirs(logdir, exist_ok=True)

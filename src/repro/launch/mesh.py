@@ -7,8 +7,18 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
+
+
+def gspmd(mesh):
+    """``mesh`` with every axis ``Auto``. The model code leaves layouts to
+    GSPMD propagation, while ``jax.make_mesh`` types axes ``Explicit`` by
+    default, under which a worker ``vmap`` over differently-sharded leaves
+    and a gather from a vocab-sharded table are refused. Every sharding
+    the train and serve steps build goes through here."""
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,11 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     two pods add a leading 'pod' axis = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return gspmd(jax.make_mesh(shape, axes))
 
 
 def make_mesh(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return gspmd(jax.make_mesh(cfg.shape, cfg.axis_names))
 
 
 def make_worker_mesh(p: int, *, simulate_host_devices: bool = False):
@@ -62,7 +72,7 @@ def make_test_mesh(devices: Optional[int] = None,
     via a subprocess; plain test runs see (1, 1))."""
     n = devices or len(jax.devices())
     model = model_axis if n % model_axis == 0 and n > 1 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return gspmd(jax.make_mesh((n // model, model), ("data", "model")))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -45,8 +45,6 @@ def parse_args(argv=None):
     ap.add_argument("--obs", default="", metavar="PATH",
                     help="record telemetry (admit/prefill/decode/retire "
                          "spans + report) to this JSONL file")
-    from repro.launch.compile_cache import add_compile_cache_arg
-    add_compile_cache_arg(ap)
     return ap.parse_args(argv)
 
 
@@ -60,7 +58,7 @@ def main(argv=None):
                 f"{len(jax.devices())} are visible; simulate with "
                 "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     from repro.launch.compile_cache import enable_compile_cache
-    cache_dir = enable_compile_cache(args.compile_cache)
+    cache_dir = enable_compile_cache()
 
     import jax
 
@@ -72,8 +70,7 @@ def main(argv=None):
 
     if args.obs:
         obs.enable(args.obs)
-    if cache_dir:
-        print(f"compile cache: {cache_dir}")
+    print(f"compile cache: {cache_dir}")
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -82,8 +82,6 @@ def parse_args(argv=None):
     ap.add_argument("--profile", default="", metavar="DIR",
                     help="capture a jax.profiler trace of the runs into "
                          "this directory (open with TensorBoard/Perfetto)")
-    from repro.launch.compile_cache import add_compile_cache_arg
-    add_compile_cache_arg(ap)
     return ap.parse_args(argv)
 
 
@@ -183,7 +181,7 @@ def main(argv=None) -> int:
         from repro.core import spmd
         spmd.force_host_devices(max(workers, 1))
     from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache(args.compile_cache)
+    enable_compile_cache()
 
     from repro.config import ConvexConfig
 

@@ -59,8 +59,6 @@ def parse_args(argv=None):
     ap.add_argument("--profile", default="", metavar="DIR",
                     help="capture a jax.profiler trace of the run into "
                          "this directory")
-    from repro.launch.compile_cache import add_compile_cache_arg
-    add_compile_cache_arg(ap)
     return ap.parse_args(argv)
 
 
@@ -71,7 +69,7 @@ def main(argv=None):
         from repro.core import spmd
         spmd.force_host_devices(args.num_workers)
     from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache(args.compile_cache)
+    enable_compile_cache()
     from repro import obs
     from repro.config import TrainConfig, get_arch
     from repro.launch import mesh as meshlib

@@ -145,9 +145,11 @@ class ServeEngine:
             raise ValueError("chunk_buckets must be non-empty")
         if params is None:
             params = model.init_params(cfg, jax.random.PRNGKey(seed))
-        self.mesh = mesh
         if mesh is not None:
+            from repro.launch.mesh import gspmd
+            mesh = gspmd(mesh)
             params, place = self._place_tp(params, mesh)
+        self.mesh = mesh
         self.params = params
         self.cache = runtime.init_cache(cfg, self.geo)
         if mesh is not None:

@@ -192,6 +192,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     ``workers`` overrides the mesh-derived worker count: W stacked worker
     copies simulated under vmap on whatever devices the mesh has (the
     single-device reference configuration of the epoch-scan runtime)."""
+    mesh = meshlib.gspmd(mesh)
     W = workers or (meshlib.worker_count(mesh, vr_workers)
                     if tcfg.vr != "none" else 1)
     M = tcfg.vr_table_size
@@ -276,8 +277,8 @@ def make_epoch_runner(cfg: ModelConfig, tcfg: TrainConfig, W: int, *,
         epoch boundary is a ``lax.pmean`` collective. The epoch's token
         block is host-precomputed ONCE (it is step-independent: the
         finite sum replays indices 0..M-1 every epoch) and shipped
-        sharded along the worker axis — the §2 partitioner workaround:
-        in-shard ``jax.random`` miscompiles on this jax version.
+        sharded along the worker axis, like the convex spmd runners'
+        host-precomputed draws (DESIGN.md §2).
 
     Returns (run_epoch, meta); meta carries the worker mesh for spmd so
     callers can place the state (``place_train_state``).
@@ -387,10 +388,9 @@ def _epoch_runner_spmd(cfg: ModelConfig, tcfg: TrainConfig, mesh,
                        fused: bool = False, interpret: bool = False):
     """One compiled executable per (cfg, tcfg, mesh, fused, interpret):
     the whole epoch scan inside a single jitted shard_map, worker state
-    donated. ``check_rep=False`` for the same reason as the convex
-    runners (core/spmd.py): the replication checker rejects carries that
-    enter unreplicated and leave pmean-replicated."""
-    from jax.experimental.shard_map import shard_map
+    donated. ``check_vma=False`` for the same reason as the convex
+    runners (core/spmd.py): the epoch carry enters unreplicated and
+    leaves pmean-replicated."""
 
     per_worker = _make_per_worker(cfg, tcfg, fused=fused,
                                   interpret=interpret)
@@ -423,10 +423,10 @@ def _epoch_runner_spmd(cfg: ModelConfig, tcfg: TrainConfig, mesh,
         return lead(p), lead(v), lead(o), step + E, losses
 
     ws, rep = P(ax), P()
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(ws, ws, ws, rep, ws),
-        out_specs=(ws, ws, ws, rep, rep), check_rep=False),
+        out_specs=(ws, ws, ws, rep, rep), check_vma=False),
         donate_argnums=(0, 1, 2))
 
 
@@ -472,6 +472,7 @@ def eval_shape_train_state(cfg: ModelConfig, tcfg: TrainConfig, W: int):
 
 def state_shardings(state_shapes, cfg: ModelConfig, tcfg: TrainConfig, mesh,
                     vr_workers: str):
+    mesh = meshlib.gspmd(mesh)
     w_axes = (meshlib.worker_axes(mesh, vr_workers)
               if tcfg.vr != "none" else ())
     spec_tree = specs.tree_specs(state_shapes, cfg,
@@ -482,6 +483,7 @@ def state_shardings(state_shapes, cfg: ModelConfig, tcfg: TrainConfig, mesh,
 
 
 def batch_sharding(mesh, tcfg: TrainConfig, vr_workers: str, *, with_fe=False):
+    mesh = meshlib.gspmd(mesh)
     w_axes = (meshlib.worker_axes(mesh, vr_workers)
               if tcfg.vr != "none" else ())
     data_axes = tuple(a for a in ("pod", "data")

@@ -172,6 +172,36 @@ def test_worker_mesh_simulation_guard(monkeypatch):
         mesh.make_worker_mesh(4, simulate_host_devices=True)
 
 
+def test_launcher_refuses_on_accelerator(monkeypatch, capsys):
+    """The one-host process tier is a CPU rehearsal: where JAX would find
+    an accelerator, the launcher refuses before starting any process."""
+    monkeypatch.setattr(launchd, "_accelerator_platform", lambda: "tpu")
+
+    def no_spawn(*a, **k):
+        raise AssertionError("the launcher started a process")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    args = launchd.parse_args(["--nprocs", "2", "--workers", "4"])
+    assert launchd.run_launcher(args) == 2
+    assert "JAX_PLATFORMS=cpu" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("pinned,probed,want", [
+    ("cpu", None, None),        # pinned to the CPU: no probe at all
+    ("", "tpu", "tpu"),
+    ("", "cpu", None),
+])
+def test_accelerator_probe(monkeypatch, pinned, probed, want):
+    monkeypatch.setenv("JAX_PLATFORMS", pinned)
+
+    def probe(argv, **kw):
+        assert probed is not None, "probed while pinned to the CPU"
+        return subprocess.CompletedProcess(argv, 0, stdout=f"{probed}\n")
+
+    monkeypatch.setattr(subprocess, "run", probe)
+    assert launchd._accelerator_platform() == want
+
+
 def test_process_worker_mesh_validates_world():
     from repro.core import spmd
     m = spmd.process_worker_mesh(1)

@@ -17,6 +17,7 @@ The load-bearing invariants (DESIGN.md §Serving):
 """
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -239,17 +240,37 @@ def test_allocator_guards():
 
 
 def test_compile_cache_env_and_flag(tmp_path, monkeypatch):
-    from repro.launch.compile_cache import ENV_VAR, enable_compile_cache
-
-    monkeypatch.delenv(ENV_VAR, raising=False)
-    assert enable_compile_cache() is None  # no-op without opt-in
-    d = tmp_path / "cc"
-    assert enable_compile_cache(str(d)) == str(d)
-    assert d.is_dir()
+    """The cache lives at $JAX_COMPILATION_CACHE_DIR when it is set, else
+    at the fixed ``<repo>/.jax_cache``; a compile under the enabled cache
+    writes an entry there."""
     import jax
-    assert jax.config.jax_compilation_cache_dir == str(d)
-    monkeypatch.setenv(ENV_VAR, str(tmp_path / "cc2"))
-    assert enable_compile_cache() == str(tmp_path / "cc2")
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache as cc
+
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.cache_dir() == os.path.join(root, ".jax_cache")
+
+    d = tmp_path / "cc"
+    monkeypatch.setenv(cc.ENV_VAR, str(d))
+    assert cc.cache_dir() == str(d)
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        assert cc.enable_compile_cache() == str(d)
+        assert jax.config.jax_compilation_cache_dir == str(d)
+        compilation_cache.reset_cache()
+        jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(5.0)).block_until_ready()
+        assert os.listdir(d), "no cache entry written"
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 # -- tensor-parallel decode (subprocess: forced 2 host devices) -----------

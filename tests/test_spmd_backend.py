@@ -283,7 +283,24 @@ def test_worker_mesh_error_names_the_flag():
         spmd.worker_mesh(4096)
 
 
-def test_force_host_devices_after_init():
+def test_worker_mesh_error_on_chip_names_the_count(monkeypatch):
+    """On a chip the workers are chips: too few fails naming the count
+    found, with no CPU simulation flag in the advice."""
+    import types
+
+    import jax
+
+    from repro.core import spmd
+
+    chip = types.SimpleNamespace(platform="tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [chip])
+    with pytest.raises(RuntimeError, match="needs 4 devices, found 1 tpu") \
+            as err:
+        spmd.worker_mesh(4)
+    assert "xla_force_host_platform_device_count" not in str(err.value)
+
+
+def test_force_host_devices_after_init(monkeypatch):
     import jax
 
     from repro.core import spmd
@@ -292,6 +309,10 @@ def test_force_host_devices_after_init():
     spmd.force_host_devices(n)      # satisfied already: no-op
     with pytest.raises(RuntimeError, match="already initialized"):
         spmd.force_host_devices(n + 4096)
+    # an accelerator's devices cannot be forced: no-op, and worker_mesh
+    # names the count it found
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spmd.force_host_devices(n + 4096)
 
 
 def test_bench_artifact_structure():
