@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.obs import stream as obs_stream
 from repro.prox import operators as proxops
@@ -58,7 +59,8 @@ def init_state(prob: Problem, eta: float, key: jax.Array,
         return (x_next, table, acc), None
 
     init = (x0, jnp.zeros((prob.n,)), jnp.zeros((prob.d,)))
-    (x, table, acc), _ = jax.lax.scan(body, init, perm)
+    with jax.named_scope("vr/init_epoch"):
+        (x, table, acc), _ = jax.lax.scan(body, init, perm)
     return VRState(x=x, table=table, gbar=acc)
 
 
@@ -80,12 +82,15 @@ def epoch(prob: Problem, state: VRState, eta: float, order: jax.Array,
     step (DESIGN.md §Fused kernels hot-path); eta — and the prox epilogue,
     when one is configured — ride in the params, so ``prox`` here only
     shapes the unfused body.
+
+    The epoch's device ops are named ``vr/local_epoch``.
     """
     if fused is not None:
         from repro.core import fused as fusedmod
-        x, table, acc, traj = fusedmod.centralvr_epoch(
-            prob.A, prob.b, prob.kind, state.x, state.table, state.gbar,
-            order, fused, track=track_iterates)
+        with jax.named_scope("vr/local_epoch"):
+            x, table, acc, traj = fusedmod.centralvr_epoch(
+                prob.A, prob.b, prob.kind, state.x, state.table, state.gbar,
+                order, fused, track=track_iterates)
         return VRState(x=x, table=table, gbar=acc), traj
 
     def body(carry, i):
@@ -99,7 +104,8 @@ def epoch(prob: Problem, state: VRState, eta: float, order: jax.Array,
         return (x_next, table, acc), (x if track_iterates else None)
 
     init = (state.x, state.table, jnp.zeros((prob.d,)))
-    (x, table, acc), traj = jax.lax.scan(body, init, order)
+    with jax.named_scope("vr/local_epoch"):
+        (x, table, acc), traj = jax.lax.scan(body, init, order)
     # permutation sampling: every index is visited exactly once, so the
     # running accumulator IS the table mean (line 11: gbar <- gtilde)
     gbar_next = acc
@@ -126,7 +132,8 @@ def epoch_uniform(prob: Problem, state: VRState, eta: float, key: jax.Array,
         table = table.at[i].set(s_new)
         return (x_next, table), (x if track_iterates else None)
 
-    (x, table), traj = jax.lax.scan(body, (state.x, state.table), idx)
+    with jax.named_scope("vr/local_epoch"):
+        (x, table), traj = jax.lax.scan(body, (state.x, state.table), idx)
     gbar_next = convex.data_grad_from_scalars(prob, table)
     return VRState(x=x, table=table, gbar=gbar_next), traj
 
@@ -142,7 +149,8 @@ def _run_scan(prob: Problem, state: VRState, eta, g0, keys, sampling: str,
               fused=None, stream: bool = False, prox=None):
     """The whole Algorithm-1 run as one executable: a scan over epochs with
     the relative-grad-norm metric computed on device.  ``state`` is donated
-    so the (n,) table and (d,) iterate/gbar update in place."""
+    so the (n,) table and (d,) iterate/gbar update in place.  The metric's
+    device ops are named ``vr/rel_grad_norm``."""
 
     def one_epoch(state, xs):
         i, k = xs if stream else (None, xs)
@@ -154,7 +162,9 @@ def _run_scan(prob: Problem, state: VRState, eta, g0, keys, sampling: str,
         else:
             new_state, _ = epoch_uniform(prob, state, eta, k, fused=fused,
                                          prox=prox)
-        rel = convex.rel_grad_norm(prob, new_state.x, g0, prox=prox, eta=eta)
+        with jax.named_scope("vr/rel_grad_norm"):
+            rel = convex.rel_grad_norm(prob, new_state.x, g0, prox=prox,
+                                       eta=eta)
         if stream:
             obs_stream.scan_metric("rel", i, rel)
         return new_state, rel
@@ -200,13 +210,17 @@ def run(prob: Problem, *, eta: float, epochs: int, key: jax.Array,
     # the kernel epilogue; ``px`` still shapes the init epoch, the metric,
     # and the unfused body — the epoch dispatcher ignores it when fused
     fused_t = fusedmod.make_params(spec.fused, eta, prob.lam, prox=px)
-    k_init, k_run = jax.random.split(key)
-    state = init_state(prob, eta, k_init, x0=x0, prox=px)
-    g0 = convex.grad_norm0(prob, prox=px, eta=eta)
-    keys = jax.random.split(k_run, epochs)
-    state, rels = obs_stage.staged_call(
-        _run_scan, prob, state, eta, g0, keys, _label="solve/centralvr",
-        sampling=sampling, fused=fused_t, prox=px,
-        stream=obs_stream.stream_active())
+    with obs_recorder.span("driver/keys"):
+        k_init, k_run = jax.random.split(key)
+        keys = jax.random.split(k_run, epochs)
+    with obs_recorder.span("driver/init"):
+        state = init_state(prob, eta, k_init, x0=x0, prox=px)
+    with obs_recorder.span("driver/grad_norm0"):
+        g0 = convex.grad_norm0(prob, prox=px, eta=eta)
+    with obs_recorder.span("driver/run"):
+        state, rels = obs_stage.staged_call(
+            _run_scan, prob, state, eta, g0, keys, _label="solve/centralvr",
+            sampling=sampling, fused=fused_t, prox=px,
+            stream=obs_stream.stream_active())
     grad_evals = prob.n * jnp.arange(2, epochs + 2)
     return state, rels, grad_evals

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.obs import stream as obs_stream
 from repro.prox import operators as proxops
@@ -134,11 +135,14 @@ def _local_centralvr_epoch(A, b, lam, kind, x, table, gbar, eta, perm,
     static ProxSpec (or None) — the proximal step is applied per local
     step, ``x <- prox_{eta*g}(x - eta*v)`` (DESIGN.md §Composite
     objectives); when ``fused`` is set the prox rides inside the kernel
-    params and this argument is ignored (the tuple carries its own copy)."""
+    params and this argument is ignored (the tuple carries its own copy).
+
+    Its device ops are named ``vr/local_epoch`` (``jax.named_scope``)."""
     if fused is not None:
         from repro.core import fused as fusedmod
-        x, table, acc, _ = fusedmod.centralvr_epoch(
-            A, b, kind, x, table, gbar, perm, fused)
+        with jax.named_scope("vr/local_epoch"):
+            x, table, acc, _ = fusedmod.centralvr_epoch(
+                A, b, kind, x, table, gbar, perm, fused)
         return x, table, acc
     prob = Problem(A, b, lam, kind)
     ns = A.shape[0]
@@ -151,11 +155,15 @@ def _local_centralvr_epoch(A, b, lam, kind, x, table, gbar, eta, perm,
         acc = acc + s_new * A[i] / ns
         return (proxops.apply_prox(prox, x - eta * v, eta), table, acc), None
 
-    (x, table, acc), _ = jax.lax.scan(body, (x, table, jnp.zeros_like(x)), perm)
+    with jax.named_scope("vr/local_epoch"):
+        (x, table, acc), _ = jax.lax.scan(body, (x, table, jnp.zeros_like(x)),
+                                          perm)
     return x, table, acc   # acc = local gtilde (data term)
 
 
 def _local_sgd_epoch(A, b, lam, kind, x, eta, perm, prox=None):
+    """The init epoch of plain SGD on one worker's shard (Alg 2 line 2);
+    its device ops are named ``vr/init_epoch``."""
     prob = Problem(A, b, lam, kind)
     ns = A.shape[0]
 
@@ -168,7 +176,8 @@ def _local_sgd_epoch(A, b, lam, kind, x, eta, perm, prox=None):
         return (proxops.apply_prox(prox, x - eta * g, eta), table, acc), None
 
     init = (x, jnp.zeros((ns,)), jnp.zeros_like(x))
-    (x, table, acc), _ = jax.lax.scan(body, init, perm)
+    with jax.named_scope("vr/init_epoch"):
+        (x, table, acc), _ = jax.lax.scan(body, init, perm)
     return x, table, acc
 
 
@@ -195,8 +204,7 @@ def sync_init(sp: ShardedProblem, eta: float, key: jax.Array,
         lambda A, b, perm: _local_sgd_epoch(A, b, sp.lam, sp.kind, x0, eta,
                                             perm, prox=prox)
     )(sp.A, sp.b, perms)
-    return SyncState(x=proxops.apply_prox(prox, xs.mean(0), eta),
-                     tables=tables, gbar=accs.mean(0))
+    return _average(xs, tables, accs, eta, prox)
 
 
 def sync_round(sp: ShardedProblem, st: SyncState, eta: float, key: jax.Array,
@@ -215,8 +223,15 @@ def sync_round(sp: ShardedProblem, st: SyncState, eta: float, key: jax.Array,
             fused=fused, prox=prox)
     )(sp.A, sp.b, st.tables, perms)
     # central node: average x and gbar (lines 16-18); on a pod: pmean
-    return SyncState(x=proxops.apply_prox(prox, xs.mean(0), eta),
-                     tables=tables, gbar=accs.mean(0))
+    return _average(xs, tables, accs, eta, prox)
+
+
+def _average(xs, tables, accs, eta, prox) -> SyncState:
+    """The central node's average of the workers' x and gbar, its device
+    ops named ``vr/average``."""
+    with jax.named_scope("vr/average"):
+        return SyncState(x=proxops.apply_prox(prox, xs.mean(0), eta),
+                         tables=tables, gbar=accs.mean(0))
 
 
 @functools.partial(jax.jit, static_argnames=("fused", "stream", "prox"),
@@ -229,7 +244,8 @@ def _sync_scan(sp: ShardedProblem, st: SyncState, eta, g0, keys, fused=None,
         i, k = xs if stream else (None, xs)
         runtime.TRACES.inc("sync_round")
         st = sync_round(sp, st, eta, k, fused=fused, prox=prox)
-        rel = convex.rel_grad_norm(merged, st.x, g0, prox=prox, eta=eta)
+        with jax.named_scope("vr/rel_grad_norm"):
+            rel = convex.rel_grad_norm(merged, st.x, g0, prox=prox, eta=eta)
         if stream:
             obs_stream.scan_metric("rel", i, rel)
         return st, rel
@@ -263,14 +279,18 @@ def run_sync(sp: ShardedProblem, *, eta: float, rounds: int, key: jax.Array,
                              fused=fused, prox=spec.prox)
     px = proxops.parse(spec.prox) if spec.prox is not None else None
     fused_t = fusedmod.make_params(spec.fused, eta, sp.lam, prox=px)
-    k_init, k_run = jax.random.split(key)
-    st = sync_init(sp, eta, k_init, prox=px)
-    g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
-    keys = jax.random.split(k_run, rounds)
-    return obs_stage.staged_call(
-        _sync_scan, sp, st, eta, g0, keys,
-        _label="solve/centralvr_sync",
-        fused=fused_t, stream=obs_stream.stream_active(), prox=px)
+    with obs_recorder.span("driver/keys"):
+        k_init, k_run = jax.random.split(key)
+        keys = jax.random.split(k_run, rounds)
+    with obs_recorder.span("driver/init"):
+        st = sync_init(sp, eta, k_init, prox=px)
+    with obs_recorder.span("driver/grad_norm0"):
+        g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
+    with obs_recorder.span("driver/run"):
+        return obs_stage.staged_call(
+            _sync_scan, sp, st, eta, g0, keys,
+            _label="solve/centralvr_sync",
+            fused=fused_t, stream=obs_stream.stream_active(), prox=px)
 
 
 # ---------------------------------------------------------------------------

@@ -586,41 +586,69 @@ def solve(spec: RunSpec, problem, *, key=None, mesh=None,
 
     import jax
 
+    from repro.obs import recorder as obs_recorder
+
+    with obs_recorder.solve_scope():
+        with obs_recorder.span("solve/prepare", algo=spec.algo,
+                               backend=spec.backend):
+            problem, eta, key = _prepare(spec, problem, key)
+        with runtime.traces_delta() as traces:
+            t0 = time.perf_counter()
+            with obs_recorder.span("solve/dispatch"):
+                state, x, rels, grad_evals, transitions = _dispatch(
+                    entry, spec, problem, eta, key, mesh, membership)
+            with obs_recorder.span("solve/wait"):
+                rels = jax.block_until_ready(rels)
+            wall = time.perf_counter() - t0
+        with obs_recorder.span("solve/finish"):
+            return _finish(entry, spec, eta, state, x, rels, grad_evals,
+                           transitions, wall, traces)
+
+
+def _prepare(spec: RunSpec, problem, key):
+    """``solve()``'s host work before the driver call: the problem in the
+    algorithm's layout, the step size and the key."""
+    import jax
+
     from repro.core import convex, distributed
     from repro.obs import recorder as obs_recorder
 
-    with obs_recorder.span("solve/build", algo=spec.algo,
-                           backend=spec.backend):
+    with obs_recorder.span("solve/coerce"):
         problem = _coerce_problem(spec, problem)
-        eta = spec.eta
-        if eta is None:
+    eta = spec.eta
+    if eta is None:
+        with obs_recorder.span("solve/auto_eta"):
             merged = (problem.merged()
                       if isinstance(problem, distributed.ShardedProblem)
                       else problem)
             eta = convex.auto_eta(merged)
-        if key is None:
-            key = jax.random.PRNGKey(spec.seed)
+    if key is None:
+        key = jax.random.PRNGKey(spec.seed)
+    return problem, eta, key
 
-    with runtime.traces_delta() as traces:
-        t0 = time.perf_counter()
-        if spec.topology == "process":
-            from repro.core import procmesh
-            state, x, rels, transitions = procmesh.solve_process(
-                spec, problem, eta, key)
-            grad_evals = None
-        elif spec.elastic:
-            from repro.core import elastic as elasticmod
-            eres = elasticmod.run_async_elastic(
-                problem, eta=eta, rounds=spec.rounds, key=key,
-                membership=membership, speeds=spec.speeds)
-            state, x, rels = eres.state, eres.state.x_c, eres.rels
-            transitions, grad_evals = eres.transitions, None
-        else:
-            state, x, rels, grad_evals = entry.call(spec, problem, eta, key,
-                                                    mesh)
-            transitions = None
-        rels = jax.block_until_ready(rels)
-        wall = time.perf_counter() - t0
+
+def _dispatch(entry, spec: RunSpec, problem, eta, key, mesh, membership):
+    """The driver call: (state, final iterate, rels, grad_evals,
+    transitions), still on the device."""
+    if spec.topology == "process":
+        from repro.core import procmesh
+        state, x, rels, transitions = procmesh.solve_process(
+            spec, problem, eta, key)
+        return state, x, rels, None, transitions
+    if spec.elastic:
+        from repro.core import elastic as elasticmod
+        eres = elasticmod.run_async_elastic(
+            problem, eta=eta, rounds=spec.rounds, key=key,
+            membership=membership, speeds=spec.speeds)
+        return eres.state, eres.state.x_c, eres.rels, None, eres.transitions
+    state, x, rels, grad_evals = entry.call(spec, problem, eta, key, mesh)
+    return state, x, rels, grad_evals, None
+
+
+def _finish(entry, spec: RunSpec, eta, state, x, rels, grad_evals,
+            transitions, wall, traces) -> RunResult:
+    """The result on the host, with its accounting and provenance."""
+    from repro.obs import recorder as obs_recorder
 
     rels = np.asarray(rels)
     if grad_evals is not None:

@@ -66,6 +66,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.prox import operators as proxops
 
@@ -170,12 +171,30 @@ def _put(mesh: Mesh, sharded_tree, replicated_tree, worker_dim=0):
     """Place worker-stacked leaves sharded along ``worker_dim`` and
     everything else replicated, so the jitted runners see consistent input
     shardings (mixing mesh-sharded and single-device-committed args is an
-    error)."""
+    error).  Inside an ``spmd/place`` span whose ``bytes`` field counts
+    what the placement moves."""
     spec = P(*([None] * worker_dim + [WORKER_AXIS]))
     shard = NamedSharding(mesh, spec)
     repl = NamedSharding(mesh, P())
-    return (jax.device_put(sharded_tree, shard),
-            jax.device_put(replicated_tree, repl))
+    moved = _moved_bytes(sharded_tree, shard) + _moved_bytes(replicated_tree,
+                                                             repl)
+    with obs_recorder.span("spmd/place", bytes=moved):
+        return (jax.device_put(sharded_tree, shard),
+                jax.device_put(replicated_tree, repl))
+
+
+def _moved_bytes(tree, sharding) -> int:
+    """Bytes of the leaves of ``tree`` not already laid out as
+    ``sharding``: those ``jax.device_put`` has to move.  Reads metadata
+    only, never the values."""
+    total = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            if not leaf.sharding.is_equivalent_to(sharding, leaf.ndim):
+                total += leaf.nbytes
+        else:
+            total += np.asarray(leaf).nbytes
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -244,12 +263,18 @@ def _sync_runner(mesh: Mesh, kind: str, fused=None, prox=None):
         A, b, perm0 = A[0], b[0], perm0[0]    # this worker's shard
         local = Problem(A, b, lam, kind)
 
+        def average(x_w, acc):
+            # the central node as collectives, named ``vr/average``
+            with jax.named_scope("vr/average"):
+                x = jax.lax.pmean(x_w, WORKER_AXIS)
+                return (proxops.apply_prox(prox, x, eta),
+                        jax.lax.pmean(acc, WORKER_AXIS))
+
         # --- init: one plain-SGD epoch per worker, then average (line 2)
         x0 = jnp.zeros((A.shape[1],), dtype=A.dtype)
         x_w, table, acc = _local_sgd_epoch(A, b, lam, kind, x0, eta, perm0,
                                            prox=prox)
-        x = proxops.apply_prox(prox, jax.lax.pmean(x_w, WORKER_AXIS), eta)
-        gbar = jax.lax.pmean(acc, WORKER_AXIS)
+        x, gbar = average(x_w, acc)
 
         # --- communication rounds (lines 4-18): local epoch, then the
         # central average of (x, gbar) as a collective pmean
@@ -258,10 +283,9 @@ def _sync_runner(mesh: Mesh, kind: str, fused=None, prox=None):
             x_w, table, acc = _local_centralvr_epoch(
                 A, b, lam, kind, x, table, gbar, eta, perm[0], fused=fused,
                 prox=prox)
-            x = proxops.apply_prox(prox, jax.lax.pmean(x_w, WORKER_AXIS),
-                                   eta)
-            gbar = jax.lax.pmean(acc, WORKER_AXIS)
-            rel = _rel_grad_norm(local, x, g0, prox=prox, eta=eta)
+            x, gbar = average(x_w, acc)
+            with jax.named_scope("vr/rel_grad_norm"):
+                rel = _rel_grad_norm(local, x, g0, prox=prox, eta=eta)
             return (x, table, gbar), rel
 
         (x, table, gbar), rels = jax.lax.scan(one_round, (x, table, gbar),
@@ -286,17 +310,21 @@ def run_sync(sp, *, eta: float, rounds: int, key: jax.Array,
     px = proxops.parse(prox) if prox is not None else None
     fused_t = fusedmod.make_params(fused, eta, sp.lam, prox=px)
     mesh = _check_mesh(mesh, sp.p)
-    k_init, k_run = jax.random.split(key)
-    g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
-    perm0 = jax.vmap(lambda kk: jax.random.permutation(kk, sp.ns))(
-        jax.random.split(k_init, sp.p))
-    perms = _round_perms(jax.random.split(k_run, rounds), sp.p, sp.ns)
+    with obs_recorder.span("driver/keys"):
+        k_init, k_run = jax.random.split(key)
+        perms = _round_perms(jax.random.split(k_run, rounds), sp.p, sp.ns)
+    with obs_recorder.span("driver/grad_norm0"):
+        g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
+    with obs_recorder.span("driver/init"):
+        perm0 = jax.vmap(lambda kk: jax.random.permutation(kk, sp.ns))(
+            jax.random.split(k_init, sp.p))
     (A, b, perm0), (lam, eta, g0) = _put(
         mesh, (sp.A, sp.b, perm0), (sp.lam, jnp.asarray(eta), g0))
     (perms,), () = _put(mesh, (perms,), (), worker_dim=1)
-    x, tables, gbar, rels = obs_stage.staged_call(
-        _sync_runner(mesh, sp.kind, fused_t, px),
-        A, b, lam, eta, g0, perm0, perms, _label="spmd/centralvr_sync")
+    with obs_recorder.span("driver/run"):
+        x, tables, gbar, rels = obs_stage.staged_call(
+            _sync_runner(mesh, sp.kind, fused_t, px),
+            A, b, lam, eta, g0, perm0, perms, _label="spmd/centralvr_sync")
     return SyncState(x=x, tables=tables, gbar=gbar), rels
 
 

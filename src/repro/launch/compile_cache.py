@@ -8,6 +8,11 @@ would never hit. Thresholds are zeroed so even sub-second programs are
 cached (the default 1s floor would skip everything the reduced configs
 compile), so a second process launch of the same program deserializes
 executables instead of recompiling.
+
+The key includes the program's metadata: the op names that
+``jax.named_scope`` gives (``vr/local_epoch``, ...) live there, and with
+the default key, which strips it, an executable cached from code with
+other names (or none) is reused, and a profile shows those names.
 """
 from __future__ import annotations
 
@@ -34,4 +39,5 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path

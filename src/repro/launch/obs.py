@@ -4,8 +4,9 @@
     python -m repro.launch.obs report run.jsonl --json summary.json
     python -m repro.launch.obs validate run.jsonl other.jsonl ...
 
-``report`` prints the span timeline (with the lower/compile/warm phase
-split), streamed-metric summaries, and the notable events (provenance,
+``report`` prints the span timeline (with the compile/warm phase split
+of the staged calls: a call's compiles, and the rest of its execute
+span), streamed-metric summaries, and the notable events (provenance,
 comms_hlo) of one run record.  ``validate`` checks every row of every
 file against the v1 schema and exits nonzero on the first violation —
 the CI telemetry-smoke lane gates on it.  Neither command imports jax.

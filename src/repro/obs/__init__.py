@@ -19,9 +19,11 @@ Pieces:
 
   * :mod:`repro.obs.recorder` — the JSONL sink (``Recorder``), module
     recorder slot (``enable``/``disable``/``active``/``recording``) and
-    the no-op-safe ``span`` helper.
-  * :mod:`repro.obs.stage`    — ``staged_call``: explicit
-    ``lower/compile/execute`` phase spans around the jitted runners.
+    ``span``, which also lands in a ``jax.profiler`` trace (with
+    ``solve_scope`` tagging one call's spans with its id).
+  * :mod:`repro.obs.stage`    — ``staged_call``: ``execute`` spans around
+    the jitted runners, through the jit cache, and a ``compile`` row for
+    each compile JAX reports during the call.
   * :mod:`repro.obs.stream`   — cadence-gated ``jax.debug.callback``
     metric streaming from inside the jitted scans.
   * :mod:`repro.obs.comms`    — analytical bytes-per-collective models.

@@ -8,8 +8,8 @@ a real mesh).  The convention matches ``roofline/analysis.py``:
 **result-shape bytes landed per worker per collective** (an all-reduce of
 a (d,) float32 buffer counts d*4 bytes, whatever the wire algorithm).
 The measured twin is the ``comms_hlo`` event ``obs.stage`` records from
-the compiled module's collective ops when telemetry is on and the run is
-staged.
+the compiled module's collective ops when telemetry is on and the call
+compiled.
 
 Per-round models (d = parameter dimension, B = bytes per element):
 

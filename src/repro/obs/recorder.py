@@ -14,12 +14,21 @@ one, and every producer in the runtime checks :func:`active` first — the
 telemetry-off hot path is the exact pre-telemetry program (DESIGN.md
 §Observability).  Zero dependencies beyond the stdlib; importing this
 module never imports jax.
+
+:func:`span` is also the program's one way into the profiler's trace: it
+always opens a ``jax.profiler.TraceAnnotation`` carrying its fields as
+event stats (a native no-op check when no profile is being taken), so the
+same span names land in a ``jax.profiler`` trace, on the device timeline's
+clock, and in the JSONL record when a recorder is active.
 """
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -29,6 +38,11 @@ SCHEMA_VERSION = 1
 
 _LOCK = threading.Lock()          # guards the module-level active recorder
 _ACTIVE: Optional["Recorder"] = None
+
+# the per-call id every span opened inside one ``solve()`` carries
+_SOLVE: contextvars.ContextVar = contextvars.ContextVar("repro_obs_solve",
+                                                        default=None)
+_SOLVE_IDS = itertools.count(1)
 
 
 class Recorder:
@@ -87,21 +101,27 @@ class Recorder:
         self._write(self._row("metric", name, step=step, value=float(value),
                               **fields))
 
+    def clock(self) -> float:
+        """Seconds since the recorder started: the clock of ``t``/``t0``."""
+        return time.perf_counter() - self._t0
+
+    def span_row(self, name: str, t0: float, dur_s: float, **fields) -> None:
+        """One ``span`` row for a phase timed elsewhere (``t0`` on
+        :meth:`clock`), with the enclosing :func:`solve_scope`'s id."""
+        self._write(self._row("span", name, t0=t0, dur_s=dur_s,
+                              **_with_solve(fields)))
+
     @contextlib.contextmanager
     def span(self, name: str, **fields):
         """Timed phase: emits one ``span`` row with ``t0``/``dur_s`` on
         exit (exceptions still close the span, flagged ``failed``)."""
-        t0 = time.perf_counter() - self._t0
+        t0 = self.clock()
         try:
             yield self
         except BaseException:
-            self._write(self._row("span", name, t0=t0,
-                                  dur_s=time.perf_counter() - self._t0 - t0,
-                                  failed=True, **fields))
+            self.span_row(name, t0, self.clock() - t0, failed=True, **fields)
             raise
-        self._write(self._row("span", name, t0=t0,
-                              dur_s=time.perf_counter() - self._t0 - t0,
-                              **fields))
+        self.span_row(name, t0, self.clock() - t0, **fields)
 
     def close(self) -> None:
         with self._lock:
@@ -152,13 +172,41 @@ def recording(path: str, *, run_id: Optional[str] = None,
         disable()
 
 
+def _with_solve(fields: dict) -> dict:
+    solve = _SOLVE.get()
+    return fields if solve is None else {"solve": solve, **fields}
+
+
+@contextlib.contextmanager
+def solve_scope():
+    """Give every span opened inside the block (in this thread or task)
+    the field ``solve``: an id fresh for each block, so that one call's
+    spans group together."""
+    token = _SOLVE.set(next(_SOLVE_IDS))
+    try:
+        yield
+    finally:
+        _SOLVE.reset(token)
+
+
 @contextlib.contextmanager
 def span(name: str, **fields):
-    """Span against the ACTIVE recorder; an exact no-op when telemetry is
-    off (so producers can wrap phases unconditionally)."""
+    """A named phase of the program, with ``fields`` (counts, labels).
+
+    It opens a ``jax.profiler.TraceAnnotation(name, **fields)`` whenever
+    jax is already imported (never importing it here), so a profile
+    taken around the call shows the span on the trace's clock with its
+    fields as event stats; with no profile running that is a native no-op
+    check. When a recorder is active it also writes one JSONL ``span``
+    row. It adds no device sync. Inside :func:`solve_scope` the span
+    carries that call's ``solve`` id."""
+    fields = _with_solve(fields)
+    jax = sys.modules.get("jax")
     rec = active()
-    if rec is None:
-        yield None
-    else:
-        with rec.span(name, **fields):
-            yield rec
+    with (jax.profiler.TraceAnnotation(name, **fields) if jax is not None
+          else contextlib.nullcontext()):
+        if rec is None:
+            yield None
+        else:
+            with rec.span(name, **fields):
+                yield rec

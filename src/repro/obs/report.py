@@ -16,7 +16,8 @@ def _fmt_s(v: float) -> str:
 
 def summarize(rows: List[dict]) -> dict:
     """Structured summary of a run record: spans (timeline order), metric
-    streams, events, and the derived compile-vs-warm split."""
+    streams, events, and the derived compile-vs-warm split (``warm_s``:
+    the staged calls' execute time less the compiles inside it)."""
     spans = sorted((r for r in rows if r["kind"] == "span"),
                    key=lambda r: r["t0"])
     metrics: dict = {}
@@ -30,15 +31,15 @@ def summarize(rows: List[dict]) -> dict:
                 m["first_step"] = r["step"]
             m["last_step"], m["last_value"] = r["step"], r["value"]
     events = [r for r in rows if r["kind"] == "event"]
+    # a staged call's compiles happen inside its execute span
     compile_s = sum(r["dur_s"] for r in spans
                     if r["name"].endswith("/compile"))
-    warm_s = sum(r["dur_s"] for r in spans
-                 if r["name"].endswith("/execute"))
-    lower_s = sum(r["dur_s"] for r in spans if r["name"].endswith("/lower"))
+    execute_s = sum(r["dur_s"] for r in spans
+                    if r["name"].endswith("/execute"))
     return {"run": rows[0]["run"] if rows else None,
             "n_rows": len(rows), "spans": spans, "metrics": metrics,
-            "events": events, "lower_s": lower_s, "compile_s": compile_s,
-            "warm_s": warm_s}
+            "events": events, "compile_s": compile_s,
+            "warm_s": execute_s - compile_s}
 
 
 def render(rows: List[dict]) -> str:
@@ -54,9 +55,8 @@ def render(rows: List[dict]) -> str:
             out.append(f"  t={r['t0']:9.3f}s  {_fmt_s(r['dur_s'])}  "
                        f"{r['name']}{flag}")
         if s["compile_s"] or s["warm_s"]:
-            out.append(f"  phase split: lower {s['lower_s']:.3f}s  "
-                       f"compile {s['compile_s']:.3f}s  "
-                       f"warm(execute) {s['warm_s']:.3f}s")
+            out.append(f"  phase split: compile {s['compile_s']:.3f}s  "
+                       f"warm(execute - compile) {s['warm_s']:.3f}s")
 
     if s["metrics"]:
         out.append("")

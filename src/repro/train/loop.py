@@ -99,9 +99,9 @@ def run_training(cfg: ModelConfig, tcfg: TrainConfig, *,
         if rec is None:
             state, losses = run_epoch(state)
         elif e == start_epoch:
-            # first epoch staged (lower/compile/execute spans split compile
-            # from warm cost; the spmd wrapper is not AOT-stageable and
-            # falls back to a plain execute span)
+            # first epoch staged (an execute span, with a compile row for
+            # each compile inside it; the spmd wrapper has no .lower, so
+            # its compiled module is not read)
             state, losses = obs_stage.staged_call(run_epoch, state,
                                                   _label="train/epoch")
         else:

@@ -1,14 +1,19 @@
 """Structured run telemetry pins (``repro.obs``, DESIGN.md
 §Observability).
 
-Four layers:
+Five layers:
 
   * recorder/schema — JSONL rows round-trip through the pinned v1 schema;
     the stream cadence gate and the jax-free import contract hold;
   * the central guarantee — a telemetry-ON solve is BIT-identical to the
     telemetry-off run (same trajectory, same iterate), while its record
-    carries the real lower/compile/execute span split, streamed per-round
-    metrics, and the provenance event;
+    carries the runner's compile/execute split, streamed per-round
+    metrics, and the provenance event; a warm recorded solve compiles
+    nothing;
+  * spans on the profiler's clock — solve()'s and the drivers' spans land
+    in a ``jax.profiler`` trace with their fields, one ``solve`` id per
+    call, and the VR loops carry their ``jax.named_scope`` names, also
+    through the persistent compilation cache;
   * the analytical models — fetch-staleness/wave stats of the
     deterministic event schedule and bytes-per-collective comms, pinned
     against hand-computed values, with ``comms._MODELS`` covering the
@@ -21,6 +26,7 @@ import json
 import os
 import subprocess
 import sys
+import textwrap
 import threading
 
 import jax
@@ -142,10 +148,11 @@ def test_recorded_async_solve_is_bit_identical_with_full_record(tmp_path):
     rows = schema.load_rows(path)
     schema.validate_rows(rows)
     s = report.summarize(rows)
-    # the staged path always re-lowers, so the split is real and nonzero
-    assert s["lower_s"] > 0 and s["compile_s"] > 0 and s["warm_s"] > 0
+    # the recorded call compiles its streaming runner (``stream`` is a
+    # static argument), so the split shows a real compile inside execute
+    assert s["compile_s"] > 0 and s["warm_s"] > 0
     names = {r["name"] for r in rows if r["kind"] == "span"}
-    assert {"solve/centralvr_async/lower", "solve/centralvr_async/compile",
+    assert {"solve/centralvr_async/compile",
             "solve/centralvr_async/execute"} <= names
     # one streamed metric row per recorded round
     assert s["metrics"]["rel"]["count"] == int(np.asarray(on.rels).size)
@@ -235,6 +242,259 @@ def test_train_loop_emits_structured_epoch_rows(tmp_path):
     done = [r for r in rows if r["name"] == "train_done"]
     assert len(done) == 1 and done[0]["epochs"] == 2
     assert done[0]["eval_loss"] == pytest.approx(res.final_eval_loss)
+
+
+# ---------------------------------------------------------------------------
+# Spans inside solve(), on the profiler's clock; named device loops
+# ---------------------------------------------------------------------------
+
+# solve()'s spans (parent -> children) for a vmap centralvr_sync solve with
+# eta=None; every one carries the call's ``solve`` id
+SOLVE_SPANS = {
+    "solve/prepare": {"solve/coerce", "solve/auto_eta"},
+    "solve/dispatch": {"driver/keys", "driver/init", "driver/grad_norm0",
+                       "driver/run"},
+    "solve/wait": set(),
+    "solve/finish": set(),
+}
+PROGRAM_PREFIXES = ("solve/", "driver/", "spmd/")
+
+
+def _profiled_spans(logdir):
+    """(name, start_ns, end_ns, stats) of the program's host spans in the
+    one ``.xplane.pb`` under ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PROGRAM_PREFIXES):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                {k: v for k, v in e.stats}))
+    return out
+
+
+def test_solve_spans_land_in_the_profiler_trace(tmp_path):
+    """Under ``jax.profiler`` one solve() shows prepare (coerce, auto_eta),
+    dispatch (the driver's keys/init/grad_norm0/run), wait and finish, in
+    that order, nested as named, all with one ``solve`` id: and with no
+    recorder active."""
+    sp = _sharded(p=2)
+    spec = RunSpec(algo="centralvr_sync", p=2, rounds=3)
+    solve(spec, sp)                     # compile outside the profile
+    with jax.profiler.trace(str(tmp_path)):
+        solve(spec, sp)
+    spans = _profiled_spans(str(tmp_path))
+    by_name = {n: (s, e, st) for n, s, e, st in spans}
+    want = set(SOLVE_SPANS) | set().union(*SOLVE_SPANS.values())
+    assert want == set(by_name) and len(spans) == len(want)
+    assert len({st["solve"] for _, _, _, st in spans}) == 1
+    for parent, children in SOLVE_SPANS.items():
+        ps, pe, _ = by_name[parent]
+        for child in children:
+            cs, ce, _ = by_name[child]
+            assert ps <= cs and ce <= pe, (parent, child)
+    order = ["solve/prepare", "solve/dispatch", "solve/wait",
+             "solve/finish"]
+    starts = [by_name[n][0] for n in order]
+    assert starts == sorted(starts)
+    assert by_name["solve/prepare"][2]["algo"] == "centralvr_sync"
+
+
+def test_solve_spans_are_recorder_rows_too(tmp_path):
+    """The same names as JSONL span rows under a recorder, one ``solve``
+    id per call and a fresh one for the next call; the staged runner's
+    rows carry the id too."""
+    sp = _sharded(p=2)
+    spec = RunSpec(algo="centralvr_sync", p=2, rounds=3)
+    path = str(tmp_path / "run.jsonl")
+    with obs.recording(path):
+        solve(spec, sp)
+        solve(spec, sp)
+    rows = [r for r in schema.load_rows(path) if r["kind"] == "span"
+            and r["name"].startswith(PROGRAM_PREFIXES)]
+    ids = sorted({r["solve"] for r in rows})
+    assert len(ids) == 2
+    want = set(SOLVE_SPANS) | set().union(*SOLVE_SPANS.values())
+    staged = {"solve/centralvr_sync/execute", "solve/centralvr_sync/compile"}
+    for sid in ids:
+        names = {r["name"] for r in rows if r["solve"] == sid}
+        assert want < names and names - want <= staged
+
+
+def test_recorded_warm_solve_compiles_nothing(tmp_path):
+    """With a recorder on, a warm solve() goes through the jit cache: JAX
+    reports no compilation, and the record has an execute span, no compile
+    row and no comms_hlo event (those belong to the call that compiled)."""
+    from jax._src import dispatch
+
+    sp = _sharded(p=2, n=18, d=5)       # shapes no other test compiles
+    spec = RunSpec(algo="centralvr_sync", p=2, eta=0.05, rounds=3)
+    cold = str(tmp_path / "cold.jsonl")
+    with obs.recording(cold):
+        solve(spec, sp)
+    warm = str(tmp_path / "warm.jsonl")
+    compiles = []
+
+    def on_duration(name, secs, **_):
+        if name == dispatch.BACKEND_COMPILE_EVENT:
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        with obs.recording(warm):
+            solve(spec, sp)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    names = [r["name"] for r in schema.load_rows(warm)]
+    assert names.count("solve/centralvr_sync/execute") == 1
+    assert not any(n.endswith("/compile") for n in names)
+    assert "comms_hlo" not in names
+    assert "comms_hlo" in [r["name"] for r in schema.load_rows(cold)]
+
+
+def _scopes(lowered) -> set:
+    """The VR scope names in the op names of a lowered program: a path
+    part, wrapped as ``vmap(vr/...)`` under vmap, or a location's start."""
+    import re
+
+    text = lowered.as_text(debug_info=True)
+    return {s for s in ("vr/init_epoch", "vr/local_epoch", "vr/average",
+                        "vr/rel_grad_norm")
+            if re.search(rf"[/(\"]{s}[/)]", text)}
+
+
+def test_device_loops_are_named():
+    """The VR loops carry ``jax.named_scope`` names in the lowered programs
+    of both drivers the cells run: metadata only, the computation is the
+    same (the bit-identity test above and test_solver_api pin that)."""
+    from repro.core import centralvr, convex
+
+    sp = _sharded(p=2)
+    key = jax.random.PRNGKey(0)
+    st = distributed.sync_init(sp, 0.05, key)
+    g0 = convex.grad_norm0(sp.merged())
+    keys = jax.random.split(key, 2)
+    assert _scopes(distributed.sync_init.lower(sp, 0.05, key)) == {
+        "vr/init_epoch", "vr/average"}
+    assert _scopes(distributed._sync_scan.lower(sp, st, 0.05, g0, keys)) == {
+        "vr/local_epoch", "vr/average", "vr/rel_grad_norm"}
+    prob = sp.local(0)
+    vst = centralvr.init_state(prob, 0.05, key)
+    assert _scopes(centralvr.init_state.lower(prob, 0.05, key)) == {
+        "vr/init_epoch"}
+    assert _scopes(centralvr._run_scan.lower(
+        prob, vst, 0.05, g0, keys, sampling="permutation")) == {
+        "vr/local_epoch", "vr/rel_grad_norm"}
+
+
+CACHED_SCOPE = textwrap.dedent("""
+    import sys
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    scope = sys.argv[1]
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope(scope):
+            return (jnp.sin(x) * 3.0).sum()
+
+    text = f.lower(jnp.ones(16)).compile().as_text()
+    print(sorted(s for s in ("vr/first", "vr/second") if s in text))
+""")
+
+
+def test_persistent_cache_keeps_the_programs_own_op_names(tmp_path):
+    """Two processes compile the same computation under different scope
+    names through the persistent cache: the second executable carries its
+    own names, not those of the entry the first one cached (the cache key
+    includes the metadata)."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    seen = []
+    for scope in ("vr/first", "vr/second"):
+        r = subprocess.run([sys.executable, "-c", CACHED_SCOPE, scope],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+        assert r.returncode == 0, r.stderr
+        seen.append(r.stdout.strip().splitlines()[-1])
+    assert seen == ["['vr/first']", "['vr/second']"]
+    assert os.listdir(tmp_path / "cc")
+
+
+SPMD_SPANS = textwrap.dedent("""
+    import sys, json, os
+    sys.path.insert(0, "src")
+    from repro.core import spmd
+    spmd.force_host_devices(4)
+    import jax
+    from repro import RunSpec, obs, solve
+    from repro.config import ConvexConfig
+    from repro.core import distributed
+    from test_obs import _profiled_spans, _scopes
+
+    logdir, path = sys.argv[1], sys.argv[2]
+    cfg = ConvexConfig(problem="ridge", n=40, d=6, workers=4)
+    sp = distributed.make_distributed(jax.random.PRNGKey(0), cfg)
+    spec = RunSpec(algo="centralvr_sync", p=4, eta=0.01, rounds=3,
+                   backend="spmd")
+    solve(spec, sp)
+    with obs.recording(path), jax.profiler.trace(logdir):
+        res = solve(spec, sp)
+    p, ns, d = sp.A.shape
+    f32 = 4
+    mesh = spmd.worker_mesh(p)
+    sds = lambda shape, dt, spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=jax.sharding.NamedSharding(mesh, spec))
+    P = jax.sharding.PartitionSpec
+    runner = spmd._sync_runner(mesh, sp.kind).lower(
+        sds(sp.A.shape, "float32", P("workers")),
+        sds(sp.b.shape, "float32", P("workers")),
+        *[sds((), "float32", P())] * 3,
+        sds((p, ns), "int32", P("workers")),
+        sds((spec.rounds, p, ns), "int32", P(None, "workers")))
+    print(json.dumps({
+        "rows": [r["bytes"] for r in obs.schema.load_rows(path)
+                 if r["name"] == "spmd/place"],
+        "stats": [st["bytes"] for n, _, _, st in _profiled_spans(logdir)
+                  if n == "spmd/place"],
+        # A, b, perm0 sharded; lam, eta, g0 replicated; then the perms
+        "want": [sp.A.nbytes + sp.b.nbytes + p * ns * 4 + 3 * f32,
+                 spec.rounds * p * ns * 4],
+        "devices": len(res.state.tables.sharding.device_set),
+        "scopes": sorted(_scopes(runner))}))
+""")
+
+
+def test_spmd_place_counts_the_bytes_it_moves(tmp_path):
+    """On 4 host devices an spmd solve's two placements carry ``bytes``:
+    the sizes of the leaves moved onto the mesh (all of them here, since
+    the problem starts on one device), in the profile and the record; and
+    the spmd runner's loops and pmeans are named."""
+    env = dict(os.environ,
+               PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"),
+                                           os.path.dirname(__file__)]))
+    r = subprocess.run([sys.executable, "-c", SPMD_SPANS,
+                        str(tmp_path / "prof"), str(tmp_path / "run.jsonl")],
+                       cwd=ROOT, env=env, capture_output=True, text=True,
+                       timeout=600)
+    assert r.returncode == 0, r.stdout + r.stderr
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    assert out["rows"] == out["stats"] == out["want"]
+    # the shard_map runner names its loops and its pmeans too
+    assert out["scopes"] == ["vr/average", "vr/init_epoch", "vr/local_epoch",
+                             "vr/rel_grad_norm"]
 
 
 # ---------------------------------------------------------------------------

@@ -258,7 +258,8 @@ def test_compile_cache_env_and_flag(tmp_path, monkeypatch):
     assert cc.cache_dir() == str(d)
     keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key")
     saved = {k: getattr(jax.config, k) for k in keys}
     try:
         jax.config.update("jax_enable_compilation_cache", True)
