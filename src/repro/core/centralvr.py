@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.kernels.vr_epoch import ops as vr_epoch
 from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.obs import stream as obs_stream
@@ -46,8 +47,17 @@ class VRState(NamedTuple):
 @functools.partial(jax.jit, static_argnames=("prox",))
 def init_state(prob: Problem, eta: float, key: jax.Array,
                x0: Optional[jax.Array] = None, prox=None) -> VRState:
+    """The init epoch of plain SGD in the order ``permutation(key, n)``;
+    on a TPU (``vr_epoch.engaged``) it runs as the ``vr_epoch`` kernel
+    from a zero table, the same floats as the scan below."""
     x0 = jnp.zeros((prob.d,)) if x0 is None else x0
     perm = jax.random.permutation(key, prob.n)
+    if vr_epoch.engaged(prox=prox):
+        with jax.named_scope("vr/init_epoch"):
+            x, table, acc = vr_epoch.local_epochs(
+                prob.A[None], prob.b[None], perm[None], x0, eta, prob.lam,
+                kind=prob.kind, prox=prox)
+        return VRState(x=x[0], table=table[0], gbar=acc[0])
 
     def body(carry, i):
         x, table, acc = carry
@@ -70,8 +80,8 @@ def init_state(prob: Problem, eta: float, key: jax.Array,
 
 def epoch(prob: Problem, state: VRState, eta: float, order: jax.Array,
           *, track_iterates: bool = False, fused=None, prox=None):
-    """Run n CentralVR updates visiting ``order`` (a permutation for the
-    practical variant, i.i.d. uniform draws for the Theorem-1 variant).
+    """Run n CentralVR updates visiting ``order``, a permutation (the
+    practical variant; ``epoch_uniform`` is the Theorem-1 variant).
 
     Returns the new state (gbar <- gtilde per line 11) and, optionally, the
     iterate trajectory for Lyapunov-function measurements.
@@ -83,8 +93,21 @@ def epoch(prob: Problem, state: VRState, eta: float, order: jax.Array,
     when one is configured — ride in the params, so ``prox`` here only
     shapes the unfused body.
 
+    ``fused=None`` on a TPU, with no iterate trajectory and no prox or an
+    elementwise one (``vr_epoch.engaged``), runs this unfused algebra as
+    the ``vr_epoch`` kernel, the step loop inside one launch; elsewhere
+    as the scan below, which is the kernel's oracle.
+
     The epoch's device ops are named ``vr/local_epoch``.
     """
+    if vr_epoch.engaged(track_iterates=track_iterates, fused=fused,
+                        prox=prox):
+        with jax.named_scope("vr/local_epoch"):
+            x, table, acc = vr_epoch.local_epochs(
+                prob.A[None], prob.b[None], order[None], state.x, eta,
+                prob.lam, kind=prob.kind, table=state.table[None],
+                gbar=state.gbar, prox=prox)
+        return VRState(x=x[0], table=table[0], gbar=acc[0]), None
     if fused is not None:
         from repro.core import fused as fusedmod
         with jax.named_scope("vr/local_epoch"):
@@ -190,6 +213,10 @@ def run(prob: Problem, *, eta: float, epochs: int, key: jax.Array,
     can address every driver through one switch (DESIGN.md §2).
 
     Validation is a ``solver.RunSpec`` build (DESIGN.md §Solver API).
+
+    ``fused=False`` on a TPU with permutation sampling runs the init
+    epoch and every epoch as the ``vr_epoch`` kernel; the ``driver/init``
+    and ``driver/run`` spans say which path ran in their ``epoch`` field.
     """
     from repro.core import fused as fusedmod
     from repro.core import solver
@@ -213,11 +240,12 @@ def run(prob: Problem, *, eta: float, epochs: int, key: jax.Array,
     with obs_recorder.span("driver/keys"):
         k_init, k_run = jax.random.split(key)
         keys = jax.random.split(k_run, epochs)
-    with obs_recorder.span("driver/init"):
+    with obs_recorder.span("driver/init", epoch=vr_epoch.path(prox=px)):
         state = init_state(prob, eta, k_init, x0=x0, prox=px)
     with obs_recorder.span("driver/grad_norm0"):
         g0 = convex.grad_norm0(prob, prox=px, eta=eta)
-    with obs_recorder.span("driver/run"):
+    with obs_recorder.span("driver/run", epoch=vr_epoch.path(
+            sampling=sampling, fused=fused_t, prox=px)):
         state, rels = obs_stage.staged_call(
             _run_scan, prob, state, eta, g0, keys, _label="solve/centralvr",
             sampling=sampling, fused=fused_t, prox=px,

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.kernels.vr_epoch import ops as vr_epoch
 from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.obs import stream as obs_stream
@@ -137,6 +138,11 @@ def _local_centralvr_epoch(A, b, lam, kind, x, table, gbar, eta, perm,
     objectives); when ``fused`` is set the prox rides inside the kernel
     params and this argument is ignored (the tuple carries its own copy).
 
+    This scan is the CPU path and the oracle of the ``vr_epoch`` kernel,
+    which runs the same unfused algebra for all stacked workers at once
+    where ``vr_epoch.engaged`` holds (``sync_round``, the spmd runner);
+    on a TPU ``fused=None`` therefore runs that kernel, not this scan.
+
     Its device ops are named ``vr/local_epoch`` (``jax.named_scope``)."""
     if fused is not None:
         from repro.core import fused as fusedmod
@@ -163,7 +169,9 @@ def _local_centralvr_epoch(A, b, lam, kind, x, table, gbar, eta, perm,
 
 def _local_sgd_epoch(A, b, lam, kind, x, eta, perm, prox=None):
     """The init epoch of plain SGD on one worker's shard (Alg 2 line 2);
-    its device ops are named ``vr/init_epoch``."""
+    its device ops are named ``vr/init_epoch``. Where ``vr_epoch.engaged``
+    holds (a TPU, no prox or an elementwise one) the callers run the
+    ``vr_epoch`` kernel from a zero table instead, the same floats."""
     prob = Problem(A, b, lam, kind)
     ns = A.shape[0]
 
@@ -196,14 +204,22 @@ def sync_init(sp: ShardedProblem, eta: float, key: jax.Array,
               prox=None) -> SyncState:
     """Init with one plain-SGD epoch per worker, then average (line 2).
     With a prox, locals take prox'd SGD steps and the central average gets
-    one more prox (the wave-boundary ordering, DESIGN.md §2)."""
+    one more prox (the wave-boundary ordering, DESIGN.md §2).
+
+    On a TPU (``vr_epoch.engaged``) the p init epochs run as one
+    ``vr_epoch`` kernel call over the stacked shard."""
     keys = jax.random.split(key, sp.p)
     perms = jax.vmap(lambda k: jax.random.permutation(k, sp.ns))(keys)
     x0 = jnp.zeros((sp.d,))
-    xs, tables, accs = jax.vmap(
-        lambda A, b, perm: _local_sgd_epoch(A, b, sp.lam, sp.kind, x0, eta,
-                                            perm, prox=prox)
-    )(sp.A, sp.b, perms)
+    if vr_epoch.engaged(prox=prox):
+        with jax.named_scope("vr/init_epoch"):
+            xs, tables, accs = vr_epoch.local_epochs(
+                sp.A, sp.b, perms, x0, eta, sp.lam, kind=sp.kind, prox=prox)
+    else:
+        xs, tables, accs = jax.vmap(
+            lambda A, b, perm: _local_sgd_epoch(A, b, sp.lam, sp.kind, x0,
+                                                eta, perm, prox=prox)
+        )(sp.A, sp.b, perms)
     return _average(xs, tables, accs, eta, prox)
 
 
@@ -214,14 +230,24 @@ def sync_round(sp: ShardedProblem, st: SyncState, eta: float, key: jax.Array,
     objectives apply the prox per local step AND once more after the
     central average: the averaged iterate of prox'd locals is not itself
     a prox output (mean of sparse vectors is dense), so the wave boundary
-    re-projects it (DESIGN.md §2 ordering note)."""
+    re-projects it (DESIGN.md §2 ordering note).
+
+    With ``fused=None`` on a TPU (``vr_epoch.engaged``) the p local
+    epochs run as one ``vr_epoch`` kernel call over the stacked shard,
+    all workers stepping together; elsewhere they run the vmapped scan."""
     keys = jax.random.split(key, sp.p)
     perms = jax.vmap(lambda k: jax.random.permutation(k, sp.ns))(keys)
-    xs, tables, accs = jax.vmap(
-        lambda A, b, table, perm: _local_centralvr_epoch(
-            A, b, sp.lam, sp.kind, st.x, table, st.gbar, eta, perm,
-            fused=fused, prox=prox)
-    )(sp.A, sp.b, st.tables, perms)
+    if vr_epoch.engaged(fused=fused, prox=prox):
+        with jax.named_scope("vr/local_epoch"):
+            xs, tables, accs = vr_epoch.local_epochs(
+                sp.A, sp.b, perms, st.x, eta, sp.lam, kind=sp.kind,
+                table=st.tables, gbar=st.gbar, prox=prox)
+    else:
+        xs, tables, accs = jax.vmap(
+            lambda A, b, table, perm: _local_centralvr_epoch(
+                A, b, sp.lam, sp.kind, st.x, table, st.gbar, eta, perm,
+                fused=fused, prox=prox)
+        )(sp.A, sp.b, st.tables, perms)
     # central node: average x and gbar (lines 16-18); on a pod: pmean
     return _average(xs, tables, accs, eta, prox)
 
@@ -267,7 +293,12 @@ def run_sync(sp: ShardedProblem, *, eta: float, rounds: int, key: jax.Array,
 
     Thin wrapper contract (DESIGN.md §Solver API): argument validation is
     a ``solver.RunSpec`` build, so this signature and ``solve()`` fail
-    identically on invalid combinations."""
+    identically on invalid combinations.
+
+    ``fused=False`` on a TPU runs each round's local epochs, and the init
+    epoch, as the ``vr_epoch`` kernel (``sync_init``, ``sync_round``);
+    the ``driver/init`` and ``driver/run`` spans say which path ran in
+    their ``epoch`` field."""
     from repro.core import fused as fusedmod
     from repro.core import solver
     spec = solver.RunSpec(algo="centralvr_sync", p=sp.p, eta=float(eta),
@@ -282,11 +313,12 @@ def run_sync(sp: ShardedProblem, *, eta: float, rounds: int, key: jax.Array,
     with obs_recorder.span("driver/keys"):
         k_init, k_run = jax.random.split(key)
         keys = jax.random.split(k_run, rounds)
-    with obs_recorder.span("driver/init"):
+    with obs_recorder.span("driver/init", epoch=vr_epoch.path(prox=px)):
         st = sync_init(sp, eta, k_init, prox=px)
     with obs_recorder.span("driver/grad_norm0"):
         g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
-    with obs_recorder.span("driver/run"):
+    with obs_recorder.span("driver/run", epoch=vr_epoch.path(fused=fused_t,
+                                                     prox=px)):
         return obs_stage.staged_call(
             _sync_scan, sp, st, eta, g0, keys,
             _label="solve/centralvr_sync",

@@ -66,6 +66,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import convex, runtime
 from repro.core.convex import Problem
+from repro.kernels.vr_epoch import ops as vr_epoch
 from repro.obs import recorder as obs_recorder
 from repro.obs import stage as obs_stage
 from repro.prox import operators as proxops
@@ -256,7 +257,11 @@ def _sync_runner(mesh: Mesh, kind: str, fused=None, prox=None):
     ``fused.make_params`` and ``prox`` a static ProxSpec-or-None
     (both hashable, so they extend the cache key).  Prox placement mirrors
     ``distributed.sync_round`` exactly: per local step, then once more
-    after the central pmean (the wave-boundary ordering, DESIGN.md §2)."""
+    after the central pmean (the wave-boundary ordering, DESIGN.md §2).
+
+    On a TPU (``vr_epoch.engaged``) each chip runs its init epoch and,
+    with ``fused=None``, its round epochs as the ``vr_epoch`` kernel with
+    p = 1; elsewhere the scans of ``core/distributed.py``."""
     from repro.core.distributed import _local_centralvr_epoch, _local_sgd_epoch
 
     def body(A, b, lam, eta, g0, perm0, perms):
@@ -270,19 +275,34 @@ def _sync_runner(mesh: Mesh, kind: str, fused=None, prox=None):
                 return (proxops.apply_prox(prox, x, eta),
                         jax.lax.pmean(acc, WORKER_AXIS))
 
+        def kernel_epoch(x, perm, table=None, gbar=None):
+            x, table, acc = vr_epoch.local_epochs(
+                A[None], b[None], perm[None], x, eta, lam, kind=kind,
+                table=None if table is None else table[None], gbar=gbar,
+                prox=prox)
+            return x[0], table[0], acc[0]
+
         # --- init: one plain-SGD epoch per worker, then average (line 2)
         x0 = jnp.zeros((A.shape[1],), dtype=A.dtype)
-        x_w, table, acc = _local_sgd_epoch(A, b, lam, kind, x0, eta, perm0,
-                                           prox=prox)
+        if vr_epoch.engaged(prox=prox):
+            with jax.named_scope("vr/init_epoch"):
+                x_w, table, acc = kernel_epoch(x0, perm0)
+        else:
+            x_w, table, acc = _local_sgd_epoch(A, b, lam, kind, x0, eta,
+                                               perm0, prox=prox)
         x, gbar = average(x_w, acc)
 
         # --- communication rounds (lines 4-18): local epoch, then the
         # central average of (x, gbar) as a collective pmean
         def one_round(carry, perm):
             x, table, gbar = carry
-            x_w, table, acc = _local_centralvr_epoch(
-                A, b, lam, kind, x, table, gbar, eta, perm[0], fused=fused,
-                prox=prox)
+            if vr_epoch.engaged(fused=fused, prox=prox):
+                with jax.named_scope("vr/local_epoch"):
+                    x_w, table, acc = kernel_epoch(x, perm[0], table, gbar)
+            else:
+                x_w, table, acc = _local_centralvr_epoch(
+                    A, b, lam, kind, x, table, gbar, eta, perm[0],
+                    fused=fused, prox=prox)
             x, gbar = average(x_w, acc)
             with jax.named_scope("vr/rel_grad_norm"):
                 rel = _rel_grad_norm(local, x, g0, prox=prox, eta=eta)
@@ -303,7 +323,10 @@ def run_sync(sp, *, eta: float, rounds: int, key: jax.Array,
              mesh: Optional[Mesh] = None, fused=False, prox=None):
     """Algorithm 2 with one worker per device (DESIGN.md §2, spmd backend).
     Same RNG draws as the vmap driver (precomputed on host), so the
-    trajectories agree within reduction-order float noise."""
+    trajectories agree within reduction-order float noise. ``fused=False``
+    on a TPU runs each chip's epochs as the ``vr_epoch`` kernel; the
+    ``driver/init`` and ``driver/run`` spans say which path ran in their
+    ``epoch`` field."""
     from repro.core import fused as fusedmod
     from repro.core.distributed import SyncState
 
@@ -315,13 +338,14 @@ def run_sync(sp, *, eta: float, rounds: int, key: jax.Array,
         perms = _round_perms(jax.random.split(k_run, rounds), sp.p, sp.ns)
     with obs_recorder.span("driver/grad_norm0"):
         g0 = convex.grad_norm0(sp.merged(), prox=px, eta=eta)
-    with obs_recorder.span("driver/init"):
+    with obs_recorder.span("driver/init", epoch=vr_epoch.path(prox=px)):
         perm0 = jax.vmap(lambda kk: jax.random.permutation(kk, sp.ns))(
             jax.random.split(k_init, sp.p))
     (A, b, perm0), (lam, eta, g0) = _put(
         mesh, (sp.A, sp.b, perm0), (sp.lam, jnp.asarray(eta), g0))
     (perms,), () = _put(mesh, (perms,), (), worker_dim=1)
-    with obs_recorder.span("driver/run"):
+    with obs_recorder.span("driver/run", epoch=vr_epoch.path(fused=fused_t,
+                                                             prox=px)):
         x, tables, gbar, rels = obs_stage.staged_call(
             _sync_runner(mesh, sp.kind, fused_t, px),
             A, b, lam, eta, g0, perm0, perms, _label="spmd/centralvr_sync")

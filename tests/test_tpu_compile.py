@@ -9,6 +9,7 @@ the TPU library may be loaded by one process at a time, and describing it
 while modules are imported would hand the test workers different tests.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,13 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _has_epoch_kernel(text: str) -> bool:
+    """The ``vr_epoch`` kernel (its ``pallas_call`` name) is a custom call
+    of the compiled program."""
+    return re.search(r"%vr_epoch[.\d]* = .*custom_call_target="
+                     r'"tpu_custom_call"', text) is not None
+
+
 def test_vr_update_flat_compiles(one_chip):
     from repro.kernels.vr_update import kernel as vr_kernel
 
@@ -89,6 +97,33 @@ def test_fused_centralvr_epoch_compiles_at_ijcnn1(one_chip):
     assert _has_kernel(jax.jit(epoch).lower(*args).compile())
 
 
+@pytest.mark.parametrize("p,ns,d,kind", [
+    (4, 8_750, 22, "logistic"),      # ijcnn1.sync-p4: a worker's shard
+    (1, 35_000, 22, "logistic"),     # ijcnn1.centralvr-p1
+    (4, 115_928, 90, "ridge"),       # the MILLIONSONG cells: 167 MB
+], ids=["ijcnn1-p4", "ijcnn1-p1", "millionsong-p4"])
+@pytest.mark.parametrize("init", [False, True], ids=["vr", "init"])
+def test_vr_epoch_kernel_compiles_at_the_cells(one_chip, p, ns, d, kind,
+                                               init):
+    """The local-epoch kernel with its visit-order gather and scatter, at
+    the shards the cells run; MILLIONSONG's does not fit in VMEM, so a
+    block that overflows it shows here."""
+    from repro.kernels.vr_epoch import ops
+
+    def epoch(A, b, perm, x, eta, lam, table, gbar):
+        if init:
+            return ops.local_epochs(A, b, perm, x, eta, lam, kind=kind,
+                                    interpret=False)
+        return ops.local_epochs(A, b, perm, x, eta, lam, kind=kind,
+                                table=table, gbar=gbar, interpret=False)
+
+    f32 = functools.partial(_sds, dtype=jnp.float32, sharding=one_chip)
+    compiled = jax.jit(epoch).lower(
+        f32((p, ns, d)), f32((p, ns)), _sds((p, ns), jnp.int32, one_chip),
+        f32((d,)), f32(()), f32(()), f32((p, ns)), f32((d,))).compile()
+    assert _has_epoch_kernel(compiled.as_text())
+
+
 def test_rmsnorm_compiles_at_qwen2_7b(one_chip):
     from repro.kernels.rmsnorm import ops as rn_ops
 
@@ -112,7 +147,8 @@ def test_flash_attention_compiles_at_qwen2_7b(one_chip):
     assert _has_kernel(compiled)
 
 
-def test_spmd_sync_runner_compiles_on_four_chips(topo):
+def test_spmd_sync_runner_compiles_on_four_chips(topo, monkeypatch):
+    from repro import kernels
     from repro.core import spmd
 
     p, rounds = 4, 5
@@ -134,4 +170,17 @@ def test_spmd_sync_runner_compiles_on_four_chips(topo):
         _sds((rounds, p, ns), jnp.int32, per_round)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    # unfused, each chip's epochs are the vr_epoch kernel; the chooser
+    # reads the platform, which is this process's CPU, so it is told
+    monkeypatch.setattr(kernels, "has_pallas_support", lambda: True)
+    runner = spmd._sync_runner(mesh, "logistic", None, None)
+    text = runner.lower(
+        _sds((p, ns, d), jnp.float32, workers),
+        _sds((p, ns), jnp.float32, workers),
+        _sds((), jnp.float32, rep), _sds((), jnp.float32, rep),
+        _sds((), jnp.float32, rep),
+        _sds((p, ns), jnp.int32, workers),
+        _sds((rounds, p, ns), jnp.int32, per_round)).compile().as_text()
+    assert _has_epoch_kernel(text)
     assert "all-reduce" in text
